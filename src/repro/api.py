@@ -26,11 +26,16 @@ seed axis, sync strategies only), returning a :class:`SweepResult`.
 
 ``scenario.exec.telemetry`` opts into the observability planes
 (`repro.obs`): per-round device series riding the run's single
-device→host transfer plus host phase spans and cache counters, surfaced
+device→host transfer plus host phase spans and counter deltas, surfaced
 as ``RunResult.telemetry`` and rendered by ``python -m
-repro.obs.report``.  Off (the default) is bit-identical to the pre-obs
-program; on never changes the trajectory (pinned in
-``tests/test_obs.py``).
+repro.obs.report``.  Off (the default) compiles the same instructions as
+a program without any obs code — the ops' names carry the engines'
+always-on phase scopes — and gives bit-identical histories; on never
+changes the trajectory (pinned in ``tests/test_obs.py``).  Telemetry or
+not, every host phase of :func:`run` enters a
+``jax.profiler.TraceAnnotation`` named ``api.run/<phase>`` (``setup``,
+``lower``, ``compile``, ``run``, ``fetch``, ``history``), so a profiler
+capture puts each stretch of device idle time down to one of them.
 """
 from __future__ import annotations
 
@@ -87,7 +92,8 @@ class RunResult:
     mesh_shape: Optional[Dict[str, int]]   # None on the single-device path
     setup_s: float             # host: one-time eager setup
     compile_s: float           # host: XLA lower+compile of the scan
-    run_s: float               # host: compiled execution + fetch
+    run_s: float               # host: compiled execution, fetch
+    #                            and history extraction
     flushes: Optional[int] = None
     mean_staleness: Optional[float] = None
     peak_device_mem_mb: Optional[float] = None  # max peak allocation over
@@ -289,13 +295,19 @@ _COMPILED: Dict[Any, Any] = {}
 
 
 def _peak_device_mem_mb(devices) -> Optional[float]:
-    """Max peak allocation in MB across the devices the run used, or
-    None when the backend does not report memory stats (CPU returns
+    """Max peak memory in MB across the devices the run used: per device
+    the larger of the peak of live buffers and the peak reserved (a TPU
+    counts the compiled program's scratch in the reserved bytes only),
+    or None when the backend does not report memory stats (CPU returns
     None).  Device-0-only would under-report any run whose client shards
     are imbalanced or whose collectives stage on another device."""
-    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
-             for d in devices]
-    peaks = [float(p) for p in peaks if p is not None]
+    peaks = []
+    for d in devices:
+        st = d.memory_stats() or {}
+        got = [st[k] for k in ("peak_bytes_in_use", "peak_bytes_reserved")
+               if st.get(k) is not None]
+        if got:
+            peaks.append(float(max(got)))
     return round(max(peaks) / 1e6, 3) if peaks else None
 
 
@@ -368,16 +380,22 @@ def run(scenario: Scenario, *, verbose: bool = False, mesh=None,
         from repro.launch import mesh as mesh_lib
         mesh_lib.validate_client_sharding(mesh, caxes, cfg.num_clients)
 
-    # host-plane observability: a span tracer when telemetry is on (the
-    # spans ride RunResult.telemetry), cache counters always — counting
-    # is free and the cache tests assert on repro.obs.trace.COUNTERS
+    # host-plane observability: every phase enters a profiler annotation
+    # on the device trace's clock; a span tracer records them when
+    # telemetry is on (the spans ride RunResult.telemetry); counters
+    # always — counting is free and the cache tests assert on
+    # repro.obs.trace.COUNTERS
     telem_on = scenario.exec.telemetry
     tracer = Tracer() if telem_on else None
     counters0 = COUNTERS.snapshot() if telem_on else {}
+    compiles0 = COUNTERS.get("jax.backend_compile")
 
+    @contextlib.contextmanager
     def span(name):
-        return (tracer.span(name) if tracer is not None
-                else contextlib.nullcontext())
+        with jax.profiler.TraceAnnotation("api.run/" + name), \
+                (tracer.span(name) if tracer is not None
+                 else contextlib.nullcontext()):
+            yield
 
     t0 = time.perf_counter()
     skey = (_setup_cache_key(cfg, mesh, caxes)
@@ -406,8 +424,9 @@ def run(scenario: Scenario, *, verbose: bool = False, mesh=None,
     key = (cfg0, mesh, caxes, placement,
            jax.config.jax_default_matmul_precision)
     compiled = _COMPILED.get(key)
+    aot_hit = compiled is not None
     t0 = time.perf_counter()
-    if compiled is None:
+    if not aot_hit:
         COUNTERS.inc("api.aot_cache.miss")
         fn = eng._scan_fn(cfg0, mesh, caxes)
         with span("lower"):
@@ -424,11 +443,16 @@ def run(scenario: Scenario, *, verbose: bool = False, mesh=None,
     t0 = time.perf_counter()
     with span("run"):
         _, outs = compiled(state0, data)
+        jax.block_until_ready(outs)
+    with span("fetch"):
         outs = jax.device_get(outs)                 # the one transfer
     round_outs, scan_telem = engine.split_outputs(outs)
-    with span("fetch"):
+    with span("history"):
         history = eng.history_from_outputs(round_outs)
     run_s = time.perf_counter() - t0
+    if aot_hit and COUNTERS.get("jax.backend_compile") != compiles0:
+        # the executable was cached, yet something on the call compiled
+        COUNTERS.inc("api.compile_on_hit")
 
     if verbose:
         for r, a, l, t, e in zip(history["round"], history["acc"],

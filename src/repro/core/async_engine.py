@@ -306,104 +306,123 @@ def _scan_fn_cached(cfg: FLRunConfig, mesh, client_axes):
                                  lp, cp)
 
         def event_step(state, step):
-            r_rnd = jax.random.fold_in(state.rng, step)
+            # every op of the event sits under one async_event/* scope:
+            # select, batches, local_train, contribute, aggregate (flush,
+            # global, fetch), orbit (costs, route gating), eval (and
+            # telemetry)
+            with phase_scope("async_event/batches"):
+                r_rnd = jax.random.fold_in(state.rng, step)
 
             # ---- 1. pop the earliest-deadline cohort ---------------------
-            if full:
-                in_cohort = jnp.ones((c,), bool)
-            else:
-                _, idx = jax.lax.top_k(-state.clock, cohort)
-                cohort_idx = jnp.sort(idx)     # ascending client order
-                in_cohort = jnp.zeros((c,), bool).at[cohort_idx].set(True)
-                t_event = jnp.max(jnp.where(in_cohort, state.clock,
-                                            -jnp.inf))
+            with phase_scope("async_event/select"):
+                if full:
+                    in_cohort = jnp.ones((c,), bool)
+                else:
+                    _, idx = jax.lax.top_k(-state.clock, cohort)
+                    cohort_idx = jnp.sort(idx)     # ascending client order
+                    in_cohort = jnp.zeros((c,), bool).at[cohort_idx].set(
+                        True)
+                    t_event = jnp.max(jnp.where(in_cohort, state.clock,
+                                                -jnp.inf))
 
             # ---- 2. train the cohort on its fetched bases ----------------
             if full:
-                imgs, labs = client_batches(data.images, data.labels,
-                                            data.client_idx, r_rnd,
-                                            cfg.batch_size)
-                imgs, labs = shard_clients(imgs), shard_clients(labs)
-                trained, l_new = _local_train(
-                    state.work_params, imgs, labs, lr=cfg.lr,
-                    steps=cfg.local_steps,
-                    microbatch=cfg.client_microbatch,
-                    client_shards=(shard_rules.axis_size(mesh, caxes)
-                                   if sharded else 1))
-                trained = shard_stack(trained)
-                losses = shard_clients(l_new)
+                with phase_scope("async_event/batches"):
+                    imgs, labs = client_batches(data.images, data.labels,
+                                                data.client_idx, r_rnd,
+                                                cfg.batch_size)
+                    imgs, labs = shard_clients(imgs), shard_clients(labs)
+                with phase_scope("async_event/local_train"):
+                    trained, l_new = _local_train(
+                        state.work_params, imgs, labs, lr=cfg.lr,
+                        steps=cfg.local_steps,
+                        microbatch=cfg.client_microbatch,
+                        client_shards=(shard_rules.axis_size(mesh, caxes)
+                                       if sharded else 1))
+                    trained = shard_stack(trained)
+                    losses = shard_clients(l_new)
             else:
                 # full-width batch *indices* (bit-stable vs the cohort
                 # composition), but only the cohort's samples are gathered
                 # and only the cohort trains
-                spc = data.client_idx.shape[1]
-                picks = jax.random.randint(r_rnd, (c, cfg.batch_size),
-                                           0, spc)
-                flat = jnp.take_along_axis(data.client_idx, picks, axis=1)
-                flat_c = flat[cohort_idx]
-                imgs, labs = data.images[flat_c], data.labels[flat_c]
-                base = jax.tree_util.tree_map(lambda x: x[cohort_idx],
-                                              state.work_params)
+                with phase_scope("async_event/batches"):
+                    spc = data.client_idx.shape[1]
+                    picks = jax.random.randint(r_rnd, (c, cfg.batch_size),
+                                               0, spc)
+                    flat = jnp.take_along_axis(data.client_idx, picks,
+                                               axis=1)
+                    flat_c = flat[cohort_idx]
+                    imgs, labs = data.images[flat_c], data.labels[flat_c]
+                    base = jax.tree_util.tree_map(lambda x: x[cohort_idx],
+                                                  state.work_params)
                 # cohort stacks are gather products with no pinned layout,
                 # so the microbatch scan uses the unsharded decomposition
-                trained, l_c = _local_train(base, imgs, labs, lr=cfg.lr,
-                                            steps=cfg.local_steps,
-                                            microbatch=cfg.client_microbatch)
-                losses = shard_clients(state.losses.at[cohort_idx].set(l_c))
+                with phase_scope("async_event/local_train"):
+                    trained, l_c = _local_train(
+                        base, imgs, labs, lr=cfg.lr, steps=cfg.local_steps,
+                        microbatch=cfg.client_microbatch)
+                    losses = shard_clients(
+                        state.losses.at[cohort_idx].set(l_c))
 
             # ---- 3. contribute (per-client-clock gated, staleness-weighted)
-            tau = (state.v_cluster[state.assignment]
-                   - state.v_client).astype(jnp.float32)          # (C,)
-            s = stale_lib.decay(cfg.staleness, tau, a=cfg.staleness_a,
-                                b=cfg.staleness_b)
+            with phase_scope("async_event/contribute"):
+                tau = (state.v_cluster[state.assignment]
+                       - state.v_client).astype(jnp.float32)      # (C,)
+                s = stale_lib.decay(cfg.staleness, tau, a=cfg.staleness_a,
+                                    b=cfg.staleness_b)
             if strategy.visibility_gated:
                 # the upload happened at the client's OWN clock — validate
                 # its route against the plan row at that time, not t_event
-                tpb_up = contact_lib.route_to_ps_per_client(
-                    data.plan, state.clock,
-                    state.ps_index[state.assignment])
-                ok = in_cohort & jnp.isfinite(tpb_up)
+                with phase_scope("async_event/orbit"):
+                    tpb_up = contact_lib.route_to_ps_per_client(
+                        data.plan, state.clock,
+                        state.ps_index[state.assignment])
+                    ok = in_cohort & jnp.isfinite(tpb_up)
             else:
                 ok = in_cohort
-            contrib_w = jnp.where(ok, s, state.contrib_w)
-            if full:
-                contrib = jax.tree_util.tree_map(
-                    lambda t_, o: jnp.where(
-                        ok.reshape((-1,) + (1,) * (t_.ndim - 1)), t_, o),
-                    trained, state.contrib_params)
-            else:
-                ok_c = ok[cohort_idx]
+            with phase_scope("async_event/contribute"):
+                contrib_w = jnp.where(ok, s, state.contrib_w)
+                if full:
+                    contrib = jax.tree_util.tree_map(
+                        lambda t_, o: jnp.where(
+                            ok.reshape((-1,) + (1,) * (t_.ndim - 1)), t_, o),
+                        trained, state.contrib_params)
+                else:
+                    ok_c = ok[cohort_idx]
 
-                def scatter_ok(o, t_):
-                    keep = jnp.where(
-                        ok_c.reshape((-1,) + (1,) * (t_.ndim - 1)),
-                        t_, o[cohort_idx])
-                    return o.at[cohort_idx].set(keep)
+                    def scatter_ok(o, t_):
+                        keep = jnp.where(
+                            ok_c.reshape((-1,) + (1,) * (t_.ndim - 1)),
+                            t_, o[cohort_idx])
+                        return o.at[cohort_idx].set(keep)
 
-                contrib = jax.tree_util.tree_map(
-                    scatter_ok, state.contrib_params, trained)
-            contrib = shard_stack(contrib)
-            n_ok = jnp.sum(ok.astype(jnp.float32))
-            mean_tau = (jnp.sum(jnp.where(ok, tau, 0.0))
-                        / jnp.maximum(n_ok, 1.0))
+                    contrib = jax.tree_util.tree_map(
+                        scatter_ok, state.contrib_params, trained)
+                contrib = shard_stack(contrib)
+                n_ok = jnp.sum(ok.astype(jnp.float32))
+                mean_tau = (jnp.sum(jnp.where(ok, tau, 0.0))
+                            / jnp.maximum(n_ok, 1.0))
 
             # ---- 4. flush full buffers (one-hot segment-matmul math) -----
-            one_hot = jax.nn.one_hot(state.assignment, k,
-                                     dtype=jnp.float32)           # (C,K)
-            member_count = jnp.sum(one_hot, axis=0)               # (K,)
-            buf_count = agg.segment_sum(
-                one_hot, (contrib_w > 0).astype(jnp.float32))
-            flush = ((buf_count >= jnp.minimum(float(buffer), member_count))
-                     & (member_count > 0))
-            cluster_models = agg_spmd.buffered_flush_sharded(
-                contrib, losses, data.data_sizes, state.assignment, k,
-                contrib_w, flush, state.cluster_params,
-                loss_weighted=strategy.loss_weighted,
-                server_lr=cfg.server_lr, use_pallas=use_pallas)
-            flush_i = flush.astype(jnp.int32)
-            v_cluster = state.v_cluster + flush_i
-            commits = state.commits + flush_i
-            contrib_w = jnp.where(flush[state.assignment], 0.0, contrib_w)
+            with phase_scope("async_event/aggregate/flush"):
+                one_hot = jax.nn.one_hot(state.assignment, k,
+                                         dtype=jnp.float32)       # (C,K)
+                member_count = jnp.sum(one_hot, axis=0)           # (K,)
+                buf_count = agg.segment_sum(
+                    one_hot, (contrib_w > 0).astype(jnp.float32))
+                flush = ((buf_count >= jnp.minimum(float(buffer),
+                                                   member_count))
+                         & (member_count > 0))
+                cluster_models = agg_spmd.buffered_flush_sharded(
+                    contrib, losses, data.data_sizes, state.assignment, k,
+                    contrib_w, flush, state.cluster_params,
+                    loss_weighted=strategy.loss_weighted,
+                    server_lr=cfg.server_lr, use_pallas=use_pallas)
+                flush_i = flush.astype(jnp.int32)
+                v_cluster = state.v_cluster + flush_i
+                commits = state.commits + flush_i
+                contrib_w = jnp.where(flush[state.assignment], 0.0,
+                                      contrib_w)
 
             # ---- 5. buffered stage-2 across clusters ---------------------
             if k == 1:
@@ -412,92 +431,105 @@ def _scan_fn_cached(cfg: FLRunConfig, mesh, client_axes):
                 pending_next = state.pending_global
                 t_g = e_g = jnp.float32(0.0)
             else:
-                active = member_count > 0
-                due = (jnp.all(jnp.where(active, commits >= m, True))
-                       | state.pending_global)
+                with phase_scope("async_event/aggregate/global"):
+                    active = member_count > 0
+                    due = (jnp.all(jnp.where(active, commits >= m, True))
+                           | state.pending_global)
                 # window + exchange costs as of the last event (t_sim):
                 # the async analog of the sync engine's start-of-round
                 # evaluation (and bit-compatible with it in the
                 # full-cohort limit)
-                if strategy.visibility_gated:
-                    if isinstance(data.plan, contact_lib.ClusterContactPlan):
-                        gs_vis, gs_dist, _, ps_rows = \
-                            contact_lib.lookup_sliced(data.plan, state.t_sim)
+                with phase_scope("async_event/orbit"):
+                    if strategy.visibility_gated:
+                        if isinstance(data.plan,
+                                      contact_lib.ClusterContactPlan):
+                            gs_vis, gs_dist, _, ps_rows = \
+                                contact_lib.lookup_sliced(data.plan,
+                                                          state.t_sim)
+                        else:
+                            gs_vis, gs_dist, tpb = contact_lib.lookup(
+                                data.plan, state.t_sim)
+                            ps_rows = tpb[state.ps_index]
+                        worst = jnp.max(ps_rows, axis=0)          # (C,)
+                        score = jnp.where(gs_vis, worst, jnp.inf)
+                        gateway = jnp.argmin(score).astype(jnp.int32)
+                        window = jnp.isfinite(score[gateway])
+                        t_g, e_g = cost_lib.routed_ground_round_costs(
+                            ps_rows[:, gateway], gs_dist[gateway],
+                            model_bits=model_bits, lp=lp)
                     else:
-                        gs_vis, gs_dist, tpb = contact_lib.lookup(
-                            data.plan, state.t_sim)
-                        ps_rows = tpb[state.ps_index]
-                    worst = jnp.max(ps_rows, axis=0)              # (C,)
-                    score = jnp.where(gs_vis, worst, jnp.inf)
-                    gateway = jnp.argmin(score).astype(jnp.int32)
-                    window = jnp.isfinite(score[gateway])
-                    t_g, e_g = cost_lib.routed_ground_round_costs(
-                        ps_rows[:, gateway], gs_dist[gateway],
-                        model_bits=model_bits, lp=lp)
-                else:
-                    positions = constellation.positions(state.t_sim)
-                    gs = ground_station_position(t_s=state.t_sim)
-                    window = jnp.bool_(True)
-                    t_g, e_g = cost_lib.ground_round_costs(
-                        positions[state.ps_index], gs,
-                        model_bits=model_bits, lp=lp)
-                do_global = due & window
-                pending_next = due & ~window
-                dk = agg.segment_sum(
-                    one_hot, data.data_sizes.astype(jnp.float32))
-                cluster_models = jax.lax.cond(
-                    do_global,
-                    lambda cm: agg.broadcast_global(
-                        agg.global_aggregate(cm, dk), k),
-                    lambda cm: cm, cluster_models)
-                v_cluster = v_cluster + do_global.astype(jnp.int32)
-                commits = jnp.where(do_global, 0, commits)
+                        positions = constellation.positions(state.t_sim)
+                        gs = ground_station_position(t_s=state.t_sim)
+                        window = jnp.bool_(True)
+                        t_g, e_g = cost_lib.ground_round_costs(
+                            positions[state.ps_index], gs,
+                            model_bits=model_bits, lp=lp)
+                with phase_scope("async_event/aggregate/global"):
+                    do_global = due & window
+                    pending_next = due & ~window
+                    dk = agg.segment_sum(
+                        one_hot, data.data_sizes.astype(jnp.float32))
+                    cluster_models = jax.lax.cond(
+                        do_global,
+                        lambda cm: agg.broadcast_global(
+                            agg.global_aggregate(cm, dk), k),
+                        lambda cm: cm, cluster_models)
+                    v_cluster = v_cluster + do_global.astype(jnp.int32)
+                    commits = jnp.where(do_global, 0, commits)
 
             # ---- 6. costs + restart the cohort ---------------------------
             do_g = do_global
-            t_g_sel = jnp.where(do_g, t_g, 0.0)
-            if full:
-                # sync-identical reduction and addition order
-                t_r = jnp.max(jnp.where(in_cohort, state.dur, 0.0))
-                t_restart = (state.t_sim + (t_r + t_g_sel)
-                             + cfg.round_minutes * 60.0)
-            else:
-                # clamp to the last event: a cohort restarting right after
-                # a global-exchange event must not report time backwards
-                t_restart = jnp.maximum(
-                    state.t_sim,
-                    t_event + t_g_sel + cfg.round_minutes * 60.0)
-            e_event = jnp.sum(jnp.where(in_cohort, state.e_pending, 0.0))
-            e_new = state.e_sim + (e_event + jnp.where(do_g, e_g, 0.0))
-            dur_next, e_next = member_costs(t_restart)
-            new_clock = jnp.where(in_cohort, t_restart + dur_next,
-                                  state.clock)
-            new_dur = jnp.where(in_cohort, dur_next, state.dur)
-            new_e_pending = jnp.where(in_cohort, e_next, state.e_pending)
+            with phase_scope("async_event/orbit"):
+                t_g_sel = jnp.where(do_g, t_g, 0.0)
+                if full:
+                    # sync-identical reduction and addition order
+                    t_r = jnp.max(jnp.where(in_cohort, state.dur, 0.0))
+                    t_restart = (state.t_sim + (t_r + t_g_sel)
+                                 + cfg.round_minutes * 60.0)
+                else:
+                    # clamp to the last event: a cohort restarting right
+                    # after a global-exchange event must not report time
+                    # backwards
+                    t_restart = jnp.maximum(
+                        state.t_sim,
+                        t_event + t_g_sel + cfg.round_minutes * 60.0)
+                e_event = jnp.sum(jnp.where(in_cohort, state.e_pending,
+                                            0.0))
+                e_new = state.e_sim + (e_event + jnp.where(do_g, e_g, 0.0))
+                dur_next, e_next = member_costs(t_restart)
+                new_clock = jnp.where(in_cohort, t_restart + dur_next,
+                                      state.clock)
+                new_dur = jnp.where(in_cohort, dur_next, state.dur)
+                new_e_pending = jnp.where(in_cohort, e_next,
+                                          state.e_pending)
 
             # ---- 7. fetch: cohort re-syncs to its cluster model ----------
-            fetched = agg.broadcast_clusters(cluster_models,
-                                             state.assignment)
-            work = jax.tree_util.tree_map(
-                lambda f, w: jnp.where(
-                    in_cohort.reshape((-1,) + (1,) * (f.ndim - 1)), f, w),
-                fetched, state.work_params)
-            work = shard_stack(work)
-            v_client = jnp.where(in_cohort,
-                                 v_cluster[state.assignment],
-                                 state.v_client)
+            with phase_scope("async_event/aggregate/fetch"):
+                fetched = agg.broadcast_clusters(cluster_models,
+                                                 state.assignment)
+                work = jax.tree_util.tree_map(
+                    lambda f, w: jnp.where(
+                        in_cohort.reshape((-1,) + (1,) * (f.ndim - 1)),
+                        f, w),
+                    fetched, state.work_params)
+                work = shard_stack(work)
+                v_client = jnp.where(in_cohort,
+                                     v_cluster[state.assignment],
+                                     state.v_client)
 
             # ---- 8. eval + outputs ---------------------------------------
-            evaluated = (((step + 1) % cfg.eval_every == 0)
-                         | (step == cfg.rounds - 1))
-            acc = jax.lax.cond(
-                evaluated,
-                lambda _: lenet_accuracy(
-                    jax.tree_util.tree_map(
-                        lambda x: jnp.mean(x.astype(jnp.float32), 0), work),
-                    data.test_x, data.test_y),
-                lambda _: jnp.float32(jnp.nan), None)
-            loss_val = jnp.mean(losses)
+            with phase_scope("async_event/eval"):
+                evaluated = (((step + 1) % cfg.eval_every == 0)
+                             | (step == cfg.rounds - 1))
+                acc = jax.lax.cond(
+                    evaluated,
+                    lambda _: lenet_accuracy(
+                        jax.tree_util.tree_map(
+                            lambda x: jnp.mean(x.astype(jnp.float32), 0),
+                            work),
+                        data.test_x, data.test_y),
+                    lambda _: jnp.float32(jnp.nan), None)
+                loss_val = jnp.mean(losses)
 
             new_state = AsyncState(
                 work_params=work, contrib_params=contrib,
@@ -508,14 +540,15 @@ def _scan_fn_cached(cfg: FLRunConfig, mesh, client_axes):
                 assignment=state.assignment, ps_index=state.ps_index,
                 rng=state.rng, t_sim=t_restart, e_sim=e_new,
                 pending_global=pending_next)
-            out = AsyncOutput(acc, loss_val, t_restart, e_new, evaluated,
-                              do_g.astype(jnp.int32), jnp.sum(flush_i),
-                              mean_tau)
+            with phase_scope("async_event/eval"):
+                out = AsyncOutput(acc, loss_val, t_restart, e_new, evaluated,
+                                  do_g.astype(jnp.int32), jnp.sum(flush_i),
+                                  mean_tau)
             if not telem_on:
                 return new_state, out
 
             # ---- 9. telemetry (outputs only, nothing re-enters the carry)
-            with phase_scope("async_event/telemetry", True):
+            with phase_scope("async_event/telemetry"):
                 n_ok_i = n_ok.astype(jnp.int32)
                 stale_min = jnp.where(
                     n_ok > 0, jnp.min(jnp.where(ok, tau, jnp.inf)), 0.0)

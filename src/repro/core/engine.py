@@ -387,8 +387,10 @@ def _scan_fn_cached(cfg: FLRunConfig, mesh, client_axes):
     sample_bits = ds.img ** 2 * ds.channels * 32.0
     use_pallas = cfg.use_pallas_kernels
     telem_on = cfg.telemetry    # emit repro.obs Telemetry as extra scan
-    #                             outputs + named_scope phase markers;
-    #                             off compiles the exact pre-obs program
+    #                             outputs; the fed_step/* phase scopes
+    #                             are always on and name ops only, so
+    #                             off compiles the same instructions as
+    #                             an engine without any obs code
     if use_pallas:
         # lazy: the default path must not require jax.experimental.pallas
         from repro.kernels import ops as kernel_ops
@@ -436,18 +438,21 @@ def _scan_fn_cached(cfg: FLRunConfig, mesh, client_axes):
         def finish(state, rnd, params, assignment, centroids, ps_index,
                    reclustered, loss_val, t_r, e_r, pending_next,
                    did_global, global_model_fn, telem=None):
-            t_new = state.t_sim + t_r + cfg.round_minutes * 60.0
-            e_new = state.e_sim + e_r
-            evaluated = (((rnd + 1) % cfg.eval_every == 0)
-                         | (rnd == cfg.rounds - 1))
-            acc = jax.lax.cond(
-                evaluated,
-                lambda _: lenet_accuracy(global_model_fn(), data.test_x,
-                                         data.test_y),
-                lambda _: jnp.float32(jnp.nan), None)
+            with phase_scope("fed_step/orbit"):
+                t_new = state.t_sim + t_r + cfg.round_minutes * 60.0
+                e_new = state.e_sim + e_r
+            with phase_scope("fed_step/eval"):
+                evaluated = (((rnd + 1) % cfg.eval_every == 0)
+                             | (rnd == cfg.rounds - 1))
+                acc = jax.lax.cond(
+                    evaluated,
+                    lambda _: lenet_accuracy(global_model_fn(), data.test_x,
+                                             data.test_y),
+                    lambda _: jnp.float32(jnp.nan), None)
+            with phase_scope("fed_step/recluster"):
+                reclusters = state.reclusters + reclustered
             new_state = RoundState(params, assignment, centroids, ps_index,
-                                   state.rng, t_new, e_new,
-                                   state.reclusters + reclustered,
+                                   state.rng, t_new, e_new, reclusters,
                                    pending_next)
             out = RoundOutput(acc, loss_val, t_new, e_new, reclustered,
                               evaluated, did_global)
@@ -460,69 +465,81 @@ def _scan_fn_cached(cfg: FLRunConfig, mesh, client_axes):
         # ---- one federated round (fedhc / fedhc-nomaml / h-base / fedce
         # ----  / fedspace / isl-onboard) ----------------------------------
         def fed_step(state, rnd):
-            r_rnd = jax.random.fold_in(state.rng, rnd)
-            positions = constellation.positions(state.t_sim)
-            cadence_due = (rnd + 1) % cfg.rounds_per_global == 0
+            # every op of the round sits under one fed_step/* scope:
+            # batches, orbit (positions, drift, contact plan, costs),
+            # local_train, aggregate, recluster, eval (and telemetry)
+            with phase_scope("fed_step/batches"):
+                r_rnd = jax.random.fold_in(state.rng, rnd)
+            with phase_scope("fed_step/orbit"):
+                positions = constellation.positions(state.t_sim)
+                cadence_due = (rnd + 1) % cfg.rounds_per_global == 0
 
-            imgs, labs = client_batches(data.images, data.labels,
-                                        data.client_idx, r_rnd,
-                                        cfg.batch_size)
-            imgs, labs = shard_clients(imgs), shard_clients(labs)
+            with phase_scope("fed_step/batches"):
+                imgs, labs = client_batches(data.images, data.labels,
+                                            data.client_idx, r_rnd,
+                                            cfg.batch_size)
+                imgs, labs = shard_clients(imgs), shard_clients(labs)
 
-            # geometry drift: a satellite whose nearest centroid changed
-            # has "left" its cluster (Alg. 1) — drives the dropout rate.
-            if use_pallas:
-                nearest, _ = kernel_ops.kmeans_assign(positions,
-                                                      state.centroids)
-            else:
-                nearest = cl.assign(positions, state.centroids)
-            in_region = nearest == state.assignment
-
-            if strategy.visibility_gated:
-                # contact-plan gathers: who can route to whom *right now*
-                # (a cluster-sliced plan stores member->PS and PS-row
-                # routes directly; a factorized plan recomputes the same
-                # tuple from geometry; a full plan derives the slices)
-                if isinstance(data.plan, (contact_lib.ClusterContactPlan,
-                                          contact_lib.FactorizedContactPlan)):
-                    gs_vis, gs_dist, tpb_to_ps, ps_rows = \
-                        contact_lib.lookup_sliced(data.plan, state.t_sim)
+            with phase_scope("fed_step/orbit"):
+                # geometry drift: a satellite whose nearest centroid
+                # changed has "left" its cluster (Alg. 1) — drives the
+                # dropout rate.
+                if use_pallas:
+                    nearest, _ = kernel_ops.kmeans_assign(positions,
+                                                          state.centroids)
                 else:
-                    gs_vis, gs_dist, tpb = contact_lib.lookup(data.plan,
-                                                              state.t_sim)
-                    ps_of_member = state.ps_index[state.assignment]   # (C,)
-                    tpb_to_ps = tpb[jnp.arange(cfg.num_clients),
-                                    ps_of_member]
-                    ps_rows = tpb[state.ps_index]                     # (K,C)
-                # a member participates iff a bounded-hop ISL route to its
-                # PS exists (the PS itself always does: tpb diagonal is 0)
-                participating = jnp.isfinite(tpb_to_ps)
-                ps_tpb = ps_rows[:, state.ps_index]                   # (K,K)
-                if strategy.isl_global:
-                    # on-board consensus: needs every PS pair connected
-                    window = jnp.all(jnp.isfinite(ps_tpb))
-                    t_g, e_g = cost_lib.isl_consensus_costs(
-                        ps_tpb, model_bits=model_bits, lp=lp)
-                else:
-                    # relay gateway: the GS-visible satellite minimizing
-                    # the worst PS route (inf when none is visible)
-                    worst = jnp.max(ps_rows, axis=0)                  # (C,)
-                    score = jnp.where(gs_vis, worst, jnp.inf)
-                    gateway = jnp.argmin(score).astype(jnp.int32)
-                    window = jnp.isfinite(score[gateway])
-                    t_g, e_g = cost_lib.routed_ground_round_costs(
-                        ps_rows[:, gateway], gs_dist[gateway],
-                        model_bits=model_bits, lp=lp)
-                due = cadence_due | state.pending_global
-                do_global = due & window
-                pending_next = due & ~window
-            else:
-                gs = ground_station_position(t_s=state.t_sim)
-                participating = jnp.ones_like(in_region)
-                do_global = cadence_due
-                pending_next = state.pending_global    # stays False
+                    nearest = cl.assign(positions, state.centroids)
+                in_region = nearest == state.assignment
 
-            with phase_scope("fed_step/local_train", telem_on):
+                if strategy.visibility_gated:
+                    # contact-plan gathers: who can route to whom *right
+                    # now* (a cluster-sliced plan stores member->PS and
+                    # PS-row routes directly; a factorized plan recomputes
+                    # the same tuple from geometry; a full plan derives
+                    # the slices)
+                    if isinstance(data.plan,
+                                  (contact_lib.ClusterContactPlan,
+                                   contact_lib.FactorizedContactPlan)):
+                        gs_vis, gs_dist, tpb_to_ps, ps_rows = \
+                            contact_lib.lookup_sliced(data.plan, state.t_sim)
+                    else:
+                        gs_vis, gs_dist, tpb = contact_lib.lookup(
+                            data.plan, state.t_sim)
+                        ps_of_member = state.ps_index[state.assignment]
+                        tpb_to_ps = tpb[jnp.arange(cfg.num_clients),
+                                        ps_of_member]                 # (C,)
+                        ps_rows = tpb[state.ps_index]                 # (K,C)
+                    # a member participates iff a bounded-hop ISL route to
+                    # its PS exists (the PS itself always does: tpb
+                    # diagonal is 0)
+                    participating = jnp.isfinite(tpb_to_ps)
+                    ps_tpb = ps_rows[:, state.ps_index]               # (K,K)
+                    if strategy.isl_global:
+                        # on-board consensus: needs every PS pair connected
+                        window = jnp.all(jnp.isfinite(ps_tpb))
+                        t_g, e_g = cost_lib.isl_consensus_costs(
+                            ps_tpb, model_bits=model_bits, lp=lp)
+                    else:
+                        # relay gateway: the GS-visible satellite
+                        # minimizing the worst PS route (inf when none is
+                        # visible)
+                        worst = jnp.max(ps_rows, axis=0)              # (C,)
+                        score = jnp.where(gs_vis, worst, jnp.inf)
+                        gateway = jnp.argmin(score).astype(jnp.int32)
+                        window = jnp.isfinite(score[gateway])
+                        t_g, e_g = cost_lib.routed_ground_round_costs(
+                            ps_rows[:, gateway], gs_dist[gateway],
+                            model_bits=model_bits, lp=lp)
+                    due = cadence_due | state.pending_global
+                    do_global = due & window
+                    pending_next = due & ~window
+                else:
+                    gs = ground_station_position(t_s=state.t_sim)
+                    participating = jnp.ones_like(in_region)
+                    do_global = cadence_due
+                    pending_next = state.pending_global    # stays False
+
+            with phase_scope("fed_step/local_train"):
                 params, losses = _local_train(
                     state.params, imgs, labs, lr=cfg.lr,
                     steps=cfg.local_steps, **blocking)
@@ -530,29 +547,32 @@ def _scan_fn_cached(cfg: FLRunConfig, mesh, client_axes):
                 losses = shard_clients(losses)
             # the merged aggregation formulation: oracle math + sharding
             # pins, traced do_global, dynamic assignment (no recompile)
-            with phase_scope("fed_step/aggregate", telem_on):
+            with phase_scope("fed_step/aggregate"):
                 params = agg_spmd.hierarchical_round_sharded(
                     params, losses, data.data_sizes, state.assignment, k,
                     do_global, loss_weighted=strategy.loss_weighted,
                     participating=participating, use_pallas=use_pallas,
                     shardings=param_shardings)
-            loss_val = jnp.mean(losses)
+            with phase_scope("fed_step/local_train"):
+                loss_val = jnp.mean(losses)
 
-            if strategy.visibility_gated:
-                t_r, e_r = cost_lib.routed_cluster_round_costs(
-                    tpb_to_ps, participating, data.data_sizes, data.freqs,
-                    model_bits=model_bits, lp=lp, cp=cp)
-            else:
-                ps_positions = positions[state.ps_index][state.assignment]
-                t_r, e_r = cost_lib.cluster_round_costs(
-                    positions, ps_positions, state.assignment, participating,
-                    data.data_sizes, data.freqs, model_bits=model_bits,
-                    lp=lp, cp=cp)
-                t_g, e_g = cost_lib.ground_round_costs(
-                    positions[state.ps_index], gs, model_bits=model_bits,
-                    lp=lp)
-            t_r = t_r + jnp.where(do_global, t_g, 0.0)
-            e_r = e_r + jnp.where(do_global, e_g, 0.0)
+            with phase_scope("fed_step/orbit"):
+                if strategy.visibility_gated:
+                    t_r, e_r = cost_lib.routed_cluster_round_costs(
+                        tpb_to_ps, participating, data.data_sizes,
+                        data.freqs, model_bits=model_bits, lp=lp, cp=cp)
+                else:
+                    ps_positions = positions[state.ps_index][
+                        state.assignment]
+                    t_r, e_r = cost_lib.cluster_round_costs(
+                        positions, ps_positions, state.assignment,
+                        participating, data.data_sizes, data.freqs,
+                        model_bits=model_bits, lp=lp, cp=cp)
+                    t_g, e_g = cost_lib.ground_round_costs(
+                        positions[state.ps_index], gs,
+                        model_bits=model_bits, lp=lp)
+                t_r = t_r + jnp.where(do_global, t_g, 0.0)
+                e_r = e_r + jnp.where(do_global, e_g, 0.0)
 
             assignment, centroids, ps_index = (state.assignment,
                                                state.centroids,
@@ -560,31 +580,32 @@ def _scan_fn_cached(cfg: FLRunConfig, mesh, client_axes):
             reclustered = jnp.int32(0)
             if strategy.reclusters:
                 # ---- re-cluster check (Alg. 1 lines 14-18) ---------------
-                d_r = cl.dropout_rate(in_region, state.assignment, k)
-                fire = do_global & (jnp.max(d_r) > cfg.dropout_threshold)
-
                 def do_recluster(operand):
                     params, assignment, centroids, ps_index = operand
-                    res = cl.kmeans(positions, k,
-                                    jax.random.fold_in(data.r_kmeans, rnd))
+                    with phase_scope("kmeans"):
+                        res = cl.kmeans(
+                            positions, k,
+                            jax.random.fold_in(data.r_kmeans, rnd))
                     new_assignment = res.assignment
                     cluster_models = agg.cluster_aggregate(
                         params,
                         agg.loss_weights(losses, new_assignment, k),
                         new_assignment, k, use_pallas=use_pallas)
                     if strategy.maml:
-                        cluster_models = _meta_update_clusters(
-                            cluster_models, new_assignment, imgs, labs,
-                            k=k, alpha=cfg.maml_alpha, beta=cfg.maml_beta,
-                            **blocking)
+                        with phase_scope("maml"):
+                            cluster_models = _meta_update_clusters(
+                                cluster_models, new_assignment, imgs, labs,
+                                k=k, alpha=cfg.maml_alpha,
+                                beta=cfg.maml_beta, **blocking)
                     inherited = agg.broadcast_clusters(cluster_models,
                                                        new_assignment)
                     if strategy.maml:
                         # joining members take MAML inner steps on their own
                         # data from the meta-updated cluster model (§III-C)
-                        inherited = _adapt_members(
-                            inherited, imgs, labs, alpha=cfg.maml_alpha,
-                            **blocking)
+                        with phase_scope("adapt"):
+                            inherited = _adapt_members(
+                                inherited, imgs, labs,
+                                alpha=cfg.maml_alpha, **blocking)
                     changed = new_assignment != assignment
                     params = jax.tree_util.tree_map(
                         lambda inh, old: jnp.where(
@@ -596,17 +617,20 @@ def _scan_fn_cached(cfg: FLRunConfig, mesh, client_axes):
                 def no_recluster(operand):
                     return operand + (jnp.int32(0),)
 
-                (params, assignment, centroids, ps_index,
-                 reclustered) = jax.lax.cond(
-                    fire, do_recluster, no_recluster,
-                    (params, assignment, centroids, ps_index))
-                params = shard_params(params)
+                with phase_scope("fed_step/recluster"):
+                    d_r = cl.dropout_rate(in_region, state.assignment, k)
+                    fire = do_global & (jnp.max(d_r) > cfg.dropout_threshold)
+                    (params, assignment, centroids, ps_index,
+                     reclustered) = jax.lax.cond(
+                        fire, do_recluster, no_recluster,
+                        (params, assignment, centroids, ps_index))
+                    params = shard_params(params)
 
             telem = None
             if telem_on:
                 # outputs only: every value below is derived from round
                 # intermediates and feeds nothing back into the carry
-                with phase_scope("fed_step/telemetry", True):
+                with phase_scope("fed_step/telemetry"):
                     part_f = participating.astype(jnp.float32)
                     n_part = jnp.sum(part_f).astype(jnp.int32)
                     members = jnp.sum(jax.nn.one_hot(
@@ -648,18 +672,21 @@ def _scan_fn_cached(cfg: FLRunConfig, mesh, client_axes):
                         e_compute_j=e_cmp, e_comm_j=e_r - e_cmp,
                         hops_mean=hops_mean, hops_max=hops_max)
 
+            with phase_scope("fed_step/orbit"):
+                did_global = do_global.astype(jnp.int32)
             return finish(
                 state, rnd, params, assignment, centroids, ps_index,
-                reclustered, loss_val, t_r, e_r, pending_next,
-                do_global.astype(jnp.int32),
+                reclustered, loss_val, t_r, e_r, pending_next, did_global,
                 lambda: jax.tree_util.tree_map(
                     lambda x: jnp.mean(x.astype(jnp.float32), 0), params),
                 telem)
 
         # ---- one centralized round (c-fedavg) ----------------------------
         def central_step(state, rnd):
-            r_rnd = jax.random.fold_in(state.rng, rnd)
-            positions = constellation.positions(state.t_sim)
+            with phase_scope("fed_step/batches"):
+                r_rnd = jax.random.fold_in(state.rng, rnd)
+            with phase_scope("fed_step/orbit"):
+                positions = constellation.positions(state.t_sim)
             model = state.params
 
             def sgd(model, s):
@@ -671,46 +698,51 @@ def _scan_fn_cached(cfg: FLRunConfig, mesh, client_axes):
                     lambda a, gg: a - cfg.lr * gg, model, g)
                 return model, l
 
-            if cfg.local_steps > 0:
-                model, ls = jax.lax.scan(sgd, model,
-                                         jnp.arange(cfg.local_steps))
-                loss_val = ls[-1]
-            else:
-                # no training this round: report the current model's loss
-                picks = jax.random.randint(jax.random.fold_in(r_rnd, 0),
-                                           (cfg.batch_size,), 0, n_total)
-                loss_val = lenet_loss(
-                    model, (data.images[picks], data.labels[picks]))
+            with phase_scope("fed_step/local_train"):
+                if cfg.local_steps > 0:
+                    model, ls = jax.lax.scan(sgd, model,
+                                             jnp.arange(cfg.local_steps))
+                    loss_val = ls[-1]
+                else:
+                    # no training this round: report the current model's
+                    # loss
+                    picks = jax.random.randint(
+                        jax.random.fold_in(r_rnd, 0), (cfg.batch_size,), 0,
+                        n_total)
+                    loss_val = lenet_loss(
+                        model, (data.images[picks], data.labels[picks]))
 
-            participating = jnp.ones((cfg.num_clients,), bool)
-            server_pos = positions[state.ps_index[0]]
-            t_r, e_r = cost_lib.cfedavg_round_costs(
-                positions, server_pos, participating, data.data_sizes,
-                data.freqs, sample_bits=sample_bits,
-                server_freq_hz=cp.max_freq_hz, lp=lp, cp=cp)
+            with phase_scope("fed_step/orbit"):
+                participating = jnp.ones((cfg.num_clients,), bool)
+                server_pos = positions[state.ps_index[0]]
+                t_r, e_r = cost_lib.cfedavg_round_costs(
+                    positions, server_pos, participating, data.data_sizes,
+                    data.freqs, sample_bits=sample_bits,
+                    server_freq_hz=cp.max_freq_hz, lp=lp, cp=cp)
 
             telem = None
             if telem_on:
                 # raw-data uplink + central training: stage-1 traffic is
                 # the sample upload, compute energy is the server's
-                t_train = (jnp.sum(data.data_sizes) * cp.cycles_per_sample
-                           / cp.max_freq_hz)
-                e_train = cp.eps0 * cp.max_freq_hz * t_train
-                z = jnp.float32(0.0)
-                telem = Telemetry(
-                    cohort_size=jnp.int32(cfg.num_clients),
-                    accepted=jnp.int32(cfg.num_clients),
-                    cluster_fill=jnp.full((k,), float(cfg.num_clients),
-                                          jnp.float32),
-                    stale_min=z, stale_mean=z, stale_max=z,
-                    flushes=jnp.int32(0), did_global=jnp.int32(0),
-                    reclustered=jnp.int32(0),
-                    bits_stage1=(jnp.sum(data.data_sizes)
-                                 * sample_bits).astype(jnp.float32),
-                    bits_stage2=z,
-                    t_round_s=t_r + cfg.round_minutes * 60.0,
-                    e_compute_j=e_train, e_comm_j=e_r - e_train,
-                    hops_mean=z, hops_max=z)
+                with phase_scope("fed_step/telemetry"):
+                    t_train = (jnp.sum(data.data_sizes)
+                               * cp.cycles_per_sample / cp.max_freq_hz)
+                    e_train = cp.eps0 * cp.max_freq_hz * t_train
+                    z = jnp.float32(0.0)
+                    telem = Telemetry(
+                        cohort_size=jnp.int32(cfg.num_clients),
+                        accepted=jnp.int32(cfg.num_clients),
+                        cluster_fill=jnp.full((k,), float(cfg.num_clients),
+                                              jnp.float32),
+                        stale_min=z, stale_mean=z, stale_max=z,
+                        flushes=jnp.int32(0), did_global=jnp.int32(0),
+                        reclustered=jnp.int32(0),
+                        bits_stage1=(jnp.sum(data.data_sizes)
+                                     * sample_bits).astype(jnp.float32),
+                        bits_stage2=z,
+                        t_round_s=t_r + cfg.round_minutes * 60.0,
+                        e_compute_j=e_train, e_comm_j=e_r - e_train,
+                        hops_mean=z, hops_max=z)
 
             return finish(state, rnd, model, state.assignment,
                           state.centroids, state.ps_index, jnp.int32(0),

@@ -223,8 +223,9 @@ class ExecSpec:
     #                                   outputs (one transfer, zero extra
     #                                   syncs) + host span tracing in
     #                                   api.run -> RunResult.telemetry.
-    #                                   Off: bit-identical to the pre-obs
-    #                                   engines; on: outputs only, the
+    #                                   Off: the same instructions and
+    #                                   histories as an engine without
+    #                                   obs code; on: outputs only, the
     #                                   trajectory never changes
 
     def __post_init__(self):

@@ -38,12 +38,6 @@ from repro.fleet.store import SweepStore
 
 __all__ = ["run_grid", "execute_plan"]
 
-# counter keys the per-class report records (compile + setup activity)
-_TRACKED = ("api.aot_cache.hit", "api.aot_cache.miss",
-            "api.setup_cache.hit", "api.setup_cache.miss",
-            "engine.vmap_cache.hit", "engine.vmap_cache.miss")
-
-
 def _result_from_sweep_row(sweep, i: int, scenario, strategy,
                            run_s: float):
     """Per-cell RunResult from row ``i`` of a class SweepResult.  Timing
@@ -148,8 +142,7 @@ def execute_plan(plan: SweepPlan, store: SweepStore, *,
             entry.update(
                 wall_s=round(wall, 4),
                 per_round_s=round(wall / max(rounds, 1), 6),
-                counters={k: v for k, v in delta.items()
-                          if k in _TRACKED})
+                counters=delta)
         classes_report.append(entry)
     report = {
         "grid_name": plan.grid.name,

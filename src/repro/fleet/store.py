@@ -5,7 +5,7 @@ Layout (``results/sweeps/<grid-hash>/``)::
     grid.json         the SweepGrid manifest (verified on open: a hash
                       collision or edited grid fails loudly)
     plan.json         the expansion/equivalence-class plan (repro.fleet.plan)
-    report.json       post-execution: per-class wall / compile counters
+    report.json       post-execution: per-class wall / counter deltas
     <cell-key>.json   one RunResult per completed cell, embedded manifest
 
 A cell file is a plain ``RunResult.save`` artifact — loadable by

@@ -44,12 +44,16 @@ def _conv(x, p):
 
     Written as static slices + GEMM (rather than lax.conv) so that vmapping
     over *per-client weights* — the FL hot loop — lowers to a fast batched
-    matmul instead of CPU's slow grouped-convolution path."""
+    matmul instead of CPU's slow grouped-convolution path.  The patch
+    building is scoped ``conv_patches`` (under ``grad`` its ops read
+    ``jvp(conv_patches)`` forward and ``transpose(jvp(conv_patches))``
+    backward), so a profile names its layout work."""
     kh, kw, cin, cout = p["w"].shape
     H, W = x.shape[1], x.shape[2]
-    cols = [x[:, i:H - kh + 1 + i, j:W - kw + 1 + j, :]
-            for i in range(kh) for j in range(kw)]
-    patches = jnp.concatenate(cols, axis=-1)        # (B,H',W',kh*kw*cin)
+    with jax.named_scope("conv_patches"):
+        cols = [x[:, i:H - kh + 1 + i, j:W - kw + 1 + j, :]
+                for i in range(kh) for j in range(kw)]
+        patches = jnp.concatenate(cols, axis=-1)    # (B,H',W',kh*kw*cin)
     w = p["w"].reshape(kh * kw * cin, cout)
     return patches @ w + p["b"]
 

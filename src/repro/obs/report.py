@@ -6,17 +6,18 @@
     PYTHONPATH=src python -m repro.obs.report results/sweeps/<grid-hash>/
 
 Prints the run header (method / strategy axes / final accuracy / totals),
-the host phase-time breakdown (setup / lower / compile / run spans +
-cache counters), and — when the run was recorded with
-``ExecSpec.telemetry`` on — a round-by-round device-plane table: cohort
-composition, buffer occupancy, staleness spread, per-stage traffic, the
-compute/comm energy split, and ISL hop counts.  ``--trace`` additionally
+the host phase-time breakdown (setup / lower / compile / run / fetch /
+history spans + every counter delta of the run), and — when the run was
+recorded with ``ExecSpec.telemetry`` on — a round-by-round device-plane
+table: cohort composition, buffer occupancy, staleness spread, per-stage
+traffic, the compute/comm energy split, and ISL hop counts.  ``--trace`` additionally
 exports the Chrome trace-event JSON (open in https://ui.perfetto.dev).
 
 Pointing it at a **sweep directory** (one written by
 ``python -m repro.fleet.run``, identified by its ``grid.json``) instead
-renders the fleet view: grid header, per-compile-class table with the
-COUNTERS compile/cache deltas recorded at execution time, completion
+renders the fleet view: grid header, per-compile-class table with every
+COUNTERS delta recorded at execution time (cache hits and misses, backend
+compiles, ``api.compile_on_hit``), completion
 state, and the per-cell final-accuracy summary grouped over seeds.
 """
 from __future__ import annotations
@@ -142,13 +143,12 @@ def render_sweep(root: str) -> str:
                    f"{report['cells_skipped']} skipped in "
                    f"{report['wall_s']:.1f}s --")
         head = (" class                                    | mode | cells"
-                " | run |   wall_s | ms/round | compile counters")
+                " | run |   wall_s | ms/round | counters")
         out.append(head)
         out.append("-" * len(head))
         for e in report["classes"]:
-            ctr = ", ".join(f"{k.split('.', 1)[1]}={v}"
-                            for k, v in sorted(e.get("counters", {}).items())
-                            if "cache" in k) or "-"
+            ctr = ", ".join(f"{k}={v}" for k, v in
+                            sorted(e.get("counters", {}).items())) or "-"
             wall = f"{e['wall_s']:9.2f}" if "wall_s" in e else "        -"
             pr = (f"{e['per_round_s'] * 1e3:9.1f}"
                   if "per_round_s" in e else "        -")
@@ -176,7 +176,7 @@ def main(argv: Optional[List[str]] = None) -> int:
         prog="python -m repro.obs.report",
         description="Render a saved RunResult JSON (round table, "
                     "phase-time breakdown, Perfetto trace export) or a "
-                    "fleet sweep directory (per-class compile counters).")
+                    "fleet sweep directory (per-class counters).")
     ap.add_argument("run_json", help="path written by RunResult.save(), "
                                      "or a repro.fleet sweep directory")
     ap.add_argument("--rows", type=int, default=20,

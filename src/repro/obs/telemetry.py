@@ -12,7 +12,8 @@ The hard invariant (pinned by ``tests/test_obs.py`` and the sharded
 subprocess tests): every telemetry value is a *new output* derived from
 existing intermediates — nothing feeds back into the carry — so the model
 trajectory with telemetry on is identical to telemetry off, and telemetry
-off compiles the exact pre-obs program.
+off compiles the same instructions as an engine without any obs code;
+only the ops' names differ, carrying the engines' phase scopes.
 
 :class:`RunTelemetry` is the host-side container surfaced as
 ``RunResult.telemetry``: the fetched per-round series, the host span

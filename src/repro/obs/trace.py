@@ -1,10 +1,10 @@
-"""Host-plane span tracer + process-wide cache counters.
+"""Host-plane span tracer + process-wide cache and compile counters.
 
-:class:`Tracer` wraps host phases (setup / lower / compile / run / fetch,
-benchmark cells, ...) in nested spans recorded against one wall-clock
-epoch.  Spans optionally enter ``jax.profiler.TraceAnnotation``, so when
-the user also captures an XLA profiler trace (``jax.profiler.trace``),
-the semantic phase names line up with the XLA activity rows in Perfetto.
+:class:`Tracer` wraps host phases (setup / lower / compile / run / fetch
+/ history, benchmark cells, ...) in nested spans recorded against one
+wall-clock epoch.  (`repro.api.run` enters a
+``jax.profiler.TraceAnnotation`` per phase itself, telemetry on or off,
+so an XLA profiler capture shows the same phase boundaries.)
 ``Tracer.span_dicts()`` is the JSON-ready record that rides
 ``RunResult.telemetry``; the Chrome trace-event rendering lives in
 `obs.telemetry.RunTelemetry.to_chrome_trace`.
@@ -13,13 +13,18 @@ the semantic phase names line up with the XLA activity rows in Perfetto.
 increments ``api.setup_cache.hit/miss`` (the caller-owned ``setup_cache``
 dict) and ``api.aot_cache.hit/miss`` (the seed-normalized AOT executable
 cache) on every call — always, telemetry on or off: counting is host-side
-and free, and the cache tests assert on it directly.
+and free, and the cache tests assert on it directly.  Importing this
+module registers a ``jax.monitoring`` listener that counts every backend
+compile (or load from the persistent compilation cache) of the process
+as ``jax.backend_compile``; `repro.api.run` counts a call that hit the
+AOT cache while that counter still moved as ``api.compile_on_hit``.
 
-:func:`phase_scope` is the in-scan marker: a ``jax.named_scope`` wrapper
-the engines put around ``fed_step`` phases when telemetry is on, so HLO
-op metadata (and thus XLA profiler traces) carries the semantic phase
-names.  Disabled it is a no-op nullcontext — the telemetry-off program is
-byte-identical to the pre-obs build.
+:func:`phase_scope` is the in-scan marker: a ``jax.named_scope`` the
+engines put around every phase of a round or event (``fed_step/*``,
+``async_event/*``), so HLO op metadata (and thus the ``tf_op`` of each
+operation in an XLA profiler trace) carries the phase names.  A named
+scope changes op names only: the program compiles to the same
+instructions with or without it.
 """
 from __future__ import annotations
 
@@ -28,7 +33,10 @@ import contextlib
 import threading
 import time
 from dataclasses import dataclass, field
-from typing import Any, Dict, Iterator, List, Optional
+from typing import Any, Dict, Iterator, List
+
+import jax
+import jax.monitoring
 
 
 @dataclass
@@ -47,7 +55,7 @@ class Span:
 
 
 class Tracer:
-    """Nested wall-clock spans with optional XLA profiler annotation."""
+    """Nested wall-clock spans."""
 
     def __init__(self) -> None:
         self._epoch = time.perf_counter()
@@ -55,20 +63,9 @@ class Tracer:
         self.spans: List[Span] = []
 
     @contextlib.contextmanager
-    def span(self, name: str, annotate: bool = True,
-             **args: Any) -> Iterator[None]:
+    def span(self, name: str, **args: Any) -> Iterator[None]:
         """``with tracer.span("compile"): ...`` — records one span;
-        nesting depth follows the with-stack.  ``annotate=True`` also
-        enters ``jax.profiler.TraceAnnotation(name)`` when available, so
-        an XLA profiler capture shows the same phase boundaries."""
-        ann = None
-        if annotate:
-            try:
-                import jax.profiler
-                ann = jax.profiler.TraceAnnotation(name)
-                ann.__enter__()
-            except Exception:
-                ann = None
+        nesting depth follows the with-stack."""
         depth, self._depth = self._depth, self._depth + 1
         t0 = time.perf_counter()
         try:
@@ -76,11 +73,6 @@ class Tracer:
         finally:
             t1 = time.perf_counter()
             self._depth = depth
-            if ann is not None:
-                try:
-                    ann.__exit__(None, None, None)
-                except Exception:
-                    pass
             self.spans.append(Span(name, (t0 - self._epoch) * 1e6,
                                    (t1 - t0) * 1e6, depth, dict(args)))
 
@@ -127,13 +119,18 @@ class Counters:
 
 COUNTERS = Counters()
 
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
-def phase_scope(name: str, enabled: bool = True):
-    """``jax.named_scope(name)`` when enabled (names HLO metadata so XLA
-    profiler rows line up with engine phases), nullcontext otherwise —
-    the disabled path emits nothing and keeps the traced program
-    identical to a build without any obs import."""
-    if not enabled:
-        return contextlib.nullcontext()
-    import jax
+
+def _count_backend_compile(event: str, duration: float, **_) -> None:
+    if event == BACKEND_COMPILE_EVENT:
+        COUNTERS.inc("jax.backend_compile")
+
+
+jax.monitoring.register_event_duration_secs_listener(_count_backend_compile)
+
+
+def phase_scope(name: str):
+    """``jax.named_scope(name)``: names the HLO metadata of every op
+    traced inside, so XLA profiler rows line up with engine phases."""
     return jax.named_scope(name)

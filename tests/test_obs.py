@@ -4,7 +4,9 @@ OFF is bit-identical to the pre-obs engines, telemetry ON changes
 per-round device series shapes/semantics, the energy split
 reconciliation, JSON round-trip through RunResult.save/load, the span
 tracer, cache counters, the report CLI, and Chrome trace export."""
+import collections
 import json
+import re
 
 import numpy as np
 import pytest
@@ -123,21 +125,182 @@ def test_fedspace_hop_telemetry(fedspace_on):
     assert (t["accepted"] <= t["cohort_size"]).all()
 
 
+# ---- phase scopes in the compiled program, telemetry off -----------------
+#
+# The pre-optimization HLO names each op relative to the function that
+# holds it; a ``call`` prefixes its callee's names with its own, while
+# ``while``/``conditional`` regions keep their function's names.  Full
+# names are rebuilt along the call graph from the round (event) scan's
+# ``while`` body.
+
+_COMP = re.compile(r"^(ENTRY )?%([\w.\-]+) .*\{$")
+_INST = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = .*? ([a-z][\w\-]*)\((.*)$")
+_CALLS = re.compile(r"\b(to_apply|body|condition|calls|true_computation|"
+                    r"false_computation)=%([\w.\-]+)")
+_BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}")
+_ELEMENTWISE = {
+    "abs", "add", "and", "atan2", "cbrt", "ceil", "clamp", "compare",
+    "convert", "cosine", "divide", "erf", "exponential",
+    "exponential-minus-one", "floor", "is-finite", "log", "log-plus-one",
+    "logistic", "maximum", "minimum", "multiply", "negate", "not", "or",
+    "popcnt", "power", "remainder", "round-nearest-afz",
+    "round-nearest-even", "rsqrt", "select", "shift-left",
+    "shift-right-arithmetic", "shift-right-logical", "sign", "sine", "sqrt",
+    "subtract", "tan", "tanh", "xor"}
+_COMPUTE = _ELEMENTWISE | {
+    "dot", "convolution", "reduce", "gather", "scatter", "sort",
+    "concatenate", "slice", "dynamic-slice", "dynamic-update-slice"}
+_PHASE = re.compile(r"(^|[/(])(fed_step|async_event)/")
+SYNC_SCOPES = [r"fed_step/batches/", r"fed_step/orbit/",
+               r"fed_step/local_train/", r"fed_step/aggregate/",
+               r"fed_step/recluster/", r"fed_step/eval/",
+               r"fed_step/recluster/(.*/)?kmeans/",
+               r"fed_step/recluster/(.*/)?maml/",
+               r"fed_step/recluster/(.*/)?adapt/",
+               r"fed_step/eval/(.*/)?conv_patches/",
+               r"fed_step/local_train/(.*[/(])?jvp\(conv_patches\)",
+               r"fed_step/local_train/(.*[/(])?"
+               r"transpose\(jvp\(conv_patches\)\)"]
+ASYNC_SCOPES = [r"async_event/select/", r"async_event/batches/",
+                r"async_event/local_train/", r"async_event/contribute/",
+                r"async_event/aggregate/flush/",
+                r"async_event/aggregate/global/",
+                r"async_event/orbit/", r"async_event/eval/"]
+
+
+def _scan_hlo(method, **kw):
+    """Pre-optimization HLO of the telemetry-off scan at N=16, in client
+    blocks of 8 (the paper-scale path), with op metadata."""
+    from repro.core import async_engine, engine, strategies
+    cfg = _flat(method, num_clients=16, client_microbatch=8, **kw)
+    eng = async_engine if strategies.get(method).is_async else engine
+    state0, data = eng.setup(cfg)
+    lowered = eng._scan_fn(cfg).lower(state0, data)
+    return lowered.compiler_ir("hlo").as_hlo_module().to_string()
+
+
+def _scan_ops(text):
+    """[(opcode, full op name, in the scan's own loop body)] of every
+    instruction the scan's ``while`` body reaches."""
+    comps, entry, cur = {}, None, None
+    for line in text.splitlines():
+        m = _COMP.match(line)
+        if m:
+            cur = m.group(2)
+            comps[cur] = []
+            entry = cur if m.group(1) else entry
+            continue
+        m = _INST.match(line)
+        if cur is None or m is None:
+            continue
+        _, op, rest = m.groups()
+        name = re.search(r'op_name="([^"]*)"', rest)
+        calls = _CALLS.findall(rest)
+        b = _BRANCHES.search(rest)
+        if b:
+            calls += [("branch", c.strip().lstrip("%"))
+                      for c in b.group(1).split(",")]
+        comps[cur].append((op, name.group(1).split(";")[0] if name else "",
+                           calls))
+    loops = [i for i in comps[entry] if i[0] == "while"]
+    assert len(loops) == 1
+    body = dict(loops[0][2])["body"]
+    out, seen, todo = [], set(), [(body, "")]
+    while todo:
+        comp, prefix = todo.pop()
+        if (comp, prefix) in seen:
+            continue
+        seen.add((comp, prefix))
+        for op, name, calls in comps[comp]:
+            full = "/".join(x for x in (prefix, name) if x)
+            out.append((op, full, comp == body))
+            for kind, callee in calls:
+                if op == "call":
+                    todo.append((callee, full))
+                elif kind != "to_apply":      # reducers: scalar combiners
+                    todo.append((callee, prefix))
+    return out
+
+
+@pytest.fixture(scope="module")
+def scan_ops():
+    return {
+        "fedhc": _scan_ops(_scan_hlo("fedhc")),
+        "fedhc-async": _scan_ops(_scan_hlo("fedhc-async", async_cohort=4,
+                                           async_buffer=3)),
+        "c-fedavg": _scan_ops(_scan_hlo("c-fedavg")),
+    }
+
+
+@pytest.mark.parametrize("method, scopes", [("fedhc", SYNC_SCOPES),
+                                            ("fedhc-async", ASYNC_SCOPES)])
+def test_every_phase_scope_in_the_telemetry_off_scan(scan_ops, method,
+                                                     scopes):
+    names = [full for _, full, _ in scan_ops[method]]
+    for scope in scopes:
+        assert any(re.search(scope, n) for n in names), scope
+
+
+@pytest.mark.parametrize("method", ["fedhc", "fedhc-async", "c-fedavg"])
+def test_no_compute_op_of_the_scan_body_outside_a_phase(scan_ops, method):
+    ops = scan_ops[method]
+    # the scan's own loop body: its counter, its slice of the round
+    # index and its updates of the stacked outputs
+    own = {op for op, _, top in ops if top and op in _COMPUTE}
+    assert own <= {"add", "dynamic-slice", "dynamic-update-slice"}
+    outside = collections.Counter(
+        (op, full) for op, full, top in ops
+        if not top and op in _COMPUTE and not _PHASE.search(full))
+    assert not outside, outside.most_common(10)
+
+
 # ---- host plane: spans, counters, timing ---------------------------------
 
 
 def test_host_spans_cover_phases(fedhc_pair):
     _, on = fedhc_pair
-    names = [s["name"] for s in on.telemetry.spans]
-    assert "run" in names and "fetch" in names
+    spans = {s["name"]: s for s in on.telemetry.spans}
+    # run ends at block_until_ready, fetch is the device_get, history the
+    # numpy extraction: three spans, one after another
+    assert {"run", "fetch", "history"} <= set(spans)
+    assert (spans["run"]["ts_us"] + spans["run"]["dur_us"]
+            <= spans["fetch"]["ts_us"])
+    assert (spans["fetch"]["ts_us"] + spans["fetch"]["dur_us"]
+            <= spans["history"]["ts_us"])
     for s in on.telemetry.spans:
         assert s["dur_us"] >= 0 and s["ts_us"] >= 0
+
+
+def test_host_phases_annotate_with_telemetry_off(monkeypatch):
+    """Every api.run phase enters a profiler annotation on the device
+    trace's clock, telemetry on or off."""
+    import jax.profiler
+    seen = []
+
+    class Recorder:
+        def __init__(self, name, **kw):
+            self.name = name
+
+        def __enter__(self):
+            seen.append(self.name)
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+    res = api.run(Scenario.from_flat(_flat("h-base", rounds=2,
+                                           eval_every=2)))
+    assert res.telemetry is None
+    assert {"api.run/setup", "api.run/run", "api.run/fetch",
+            "api.run/history"} <= set(seen)
+    assert (seen.index("api.run/run") < seen.index("api.run/fetch")
+            < seen.index("api.run/history"))
 
 
 def test_tracer_nesting_and_durations():
     tr = Tracer()
     with tr.span("outer"):
-        with tr.span("inner", annotate=False):
+        with tr.span("inner"):
             pass
     spans = tr.span_dicts()
     d = {s["name"]: s for s in spans}
@@ -181,6 +344,62 @@ def test_setup_cache_second_call_hits():
     assert d2.get("api.setup_cache.hit") == 1
     assert "api.setup_cache.miss" not in d2
     assert r2.setup_s == 0.0 or r2.setup_s < 0.05  # cached setup is ~free
+
+
+def test_compile_on_hit_counts_recompiles_behind_a_cached_executable():
+    """``jax.backend_compile`` counts every backend compile of the
+    process; ``api.compile_on_hit`` counts the calls that hit the AOT
+    cache and still compiled."""
+    import jax
+    c0 = COUNTERS.get("jax.backend_compile")
+    jax.jit(lambda x: x * 3.0 + 1.0)(2.0)
+    assert COUNTERS.get("jax.backend_compile") == c0 + 1
+
+    sc = Scenario.from_flat(_flat("h-base", rounds=3, eval_every=3))
+    api.run(sc)                         # compiles, or hits: either way
+    hits0 = COUNTERS.get("api.compile_on_hit")
+    s0 = COUNTERS.snapshot()
+    api.run(sc)                         # AOT hit, every eager op cached
+    d = Counters.delta(s0, COUNTERS.snapshot())
+    assert d.get("api.aot_cache.hit") == 1
+    assert "jax.backend_compile" not in d
+    assert COUNTERS.get("api.compile_on_hit") == hits0
+
+    jax.clear_caches()                  # setup's eager ops must compile
+    s1 = COUNTERS.snapshot()
+    api.run(sc)
+    d = Counters.delta(s1, COUNTERS.snapshot())
+    assert d.get("api.aot_cache.hit") == 1
+    assert d.get("jax.backend_compile", 0) > 0
+    assert d.get("api.compile_on_hit") == 1
+
+
+def test_run_telemetry_counters_carry_every_delta():
+    sc = Scenario.from_flat(_flat("h-base", rounds=2, eval_every=2))
+    res = api.run(sc.replace(exec=ExecSpec(telemetry=True)))
+    c = res.telemetry.counters
+    assert c.get("api.aot_cache.hit", 0) + c.get("api.aot_cache.miss",
+                                                 0) == 1
+    if c.get("api.aot_cache.miss"):
+        assert c.get("jax.backend_compile", 0) >= 1
+
+
+def test_peak_device_mem_takes_reserved_bytes():
+    """On a TPU the program's scratch shows only in the reserved peak."""
+    class Dev:
+        def __init__(self, stats):
+            self.stats = stats
+
+        def memory_stats(self):
+            return self.stats
+
+    mb = 1e6
+    assert api._peak_device_mem_mb([
+        Dev({"peak_bytes_in_use": 2 * mb, "peak_bytes_reserved": 5 * mb}),
+        Dev({"peak_bytes_in_use": 3 * mb})]) == 5.0
+    assert api._peak_device_mem_mb([Dev({"peak_bytes_in_use": 2 * mb})]) \
+        == 2.0
+    assert api._peak_device_mem_mb([Dev(None)]) is None
 
 
 def test_peak_host_mem_reported(fedhc_pair):
