@@ -7,7 +7,7 @@ full-vmap local-train path and the microbatched one
 (``ExecSpec.client_microbatch``) — and reports setup / compile /
 per-round seconds plus the client-stack footprint.  Profiling the sweep
 (``--profile``) is what motivated the variants: at N=800 local training
-is ~97% of the round and superlinear in the full-vmap path (the im2col
+is ~97% of the round and superlinear in the full-vmap path (the
 activation working set blows the cache); microbatching restores linear
 scaling.  It also measures the contact-plan storage ladder — f32 vs bf16
 tables, cluster-sliced tables, and the factorized (store-nothing,

@@ -278,7 +278,7 @@ def _local_train(params_stack, images, labels, lr, steps, *,
     once; ``microbatch=m`` scans over m-client sub-blocks instead
     (`_vmap_clients`).  Results agree to float rounding, not bit for bit:
     XLA may pick a different reduction order for a different batch
-    shape.  At paper scale the full-vmap im2col activations do not fit
+    shape.  At paper scale the full-vmap activations do not fit
     a device's memory; microbatching bounds them and is what lets
     clients-per-device climb past 100.  ``client_shards`` is the
     client-axis size under SPMD (see `_client_blocks`)."""

@@ -40,22 +40,26 @@ def init_lenet(rng, in_ch: int = 1, img: int = 28, num_classes: int = 10,
 
 
 def _conv(x, p):
-    """VALID conv via im2col + matmul.
+    """VALID conv, stride 1, as one ``lax.conv_general_dilated``
+    (NHWC input, HWIO weights), plus the bias.
 
-    Written as static slices + GEMM (rather than lax.conv) so that vmapping
-    over *per-client weights* — the FL hot loop — lowers to a fast batched
-    matmul instead of CPU's slow grouped-convolution path.  The patch
-    building is scoped ``conv_patches`` (under ``grad`` its ops read
-    ``jvp(conv_patches)`` forward and ``transpose(jvp(conv_patches))``
-    backward), so a profile names its layout work."""
-    kh, kw, cin, cout = p["w"].shape
-    H, W = x.shape[1], x.shape[2]
-    with jax.named_scope("conv_patches"):
-        cols = [x[:, i:H - kh + 1 + i, j:W - kw + 1 + j, :]
-                for i in range(kh) for j in range(kw)]
-        patches = jnp.concatenate(cols, axis=-1)    # (B,H',W',kh*kw*cin)
-    w = p["w"].reshape(kh * kw * cin, cout)
-    return patches @ w + p["b"]
+    The input is cast to the weight dtype and the conv runs at the
+    default precision (on a TPU, bfloat16 inputs with float32
+    accumulation).  Under ``vmap`` over per-client weights (the FL hot
+    loop, `core.fedhc._vmap_clients`) JAX folds the block's clients into
+    the feature dimension as one grouped convolution, which XLA:TPU runs
+    with the clients on the lanes and no patches tensor; the weight and
+    input gradients come from JAX's conv transpose rules.  On the CPU it
+    is as fast or faster than im2col (16 clients' ``value_and_grad``:
+    MNIST 0.077 s against 0.108 s, CIFAR 0.160 s against 0.259 s).  The
+    body is scoped ``lenet_conv`` (under ``grad`` its ops read
+    ``jvp(lenet_conv)`` forward and ``transpose(jvp(lenet_conv))``
+    backward), so a profile names the layer."""
+    with jax.named_scope("lenet_conv"):
+        y = jax.lax.conv_general_dilated(
+            x.astype(p["w"].dtype), p["w"], window_strides=(1, 1),
+            padding="VALID", dimension_numbers=("NHWC", "HWIO", "NHWC"))
+        return y + p["b"]
 
 
 def _pool(x):

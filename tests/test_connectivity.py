@@ -34,10 +34,11 @@ def test_always_path_pinned_to_pre_connectivity_engine(method):
     is a verbatim `engine.run` capture at that commit).  The capture
     predates jax's `jax_threefry_partitionable=True` default, which
     changed every random stream (data, init, CPU frequencies), so the run
-    uses the old default.  One field was re-captured: c-fedavg's acc,
-    which XLA drift moved by two of the 256 eval samples at round 15
-    (0.1953125 -> 0.1875) and one at round 20 (0.3203125 -> 0.31640625);
-    the engine as it was before the TPU bring-up reads the same."""
+    uses the old default.  c-fedavg's acc sits on the rounding edge of
+    two of the 256 eval samples at round 15 (0.1953125 or 0.1875) and one
+    at round 20 (0.3203125 or 0.31640625): XLA drift once moved it to the
+    lower pair, and LeNet's conv as one ``lax.conv`` (another summation
+    order than the im2col matmul) reads the capture's own values again."""
     with open(GOLDEN) as f:
         golden = json.load(f)[method]
     with jax.threefry_partitionable(False):

@@ -157,10 +157,10 @@ SYNC_SCOPES = [r"fed_step/batches/", r"fed_step/orbit/",
                r"fed_step/recluster/(.*/)?kmeans/",
                r"fed_step/recluster/(.*/)?maml/",
                r"fed_step/recluster/(.*/)?adapt/",
-               r"fed_step/eval/(.*/)?conv_patches/",
-               r"fed_step/local_train/(.*[/(])?jvp\(conv_patches\)",
+               r"fed_step/eval/(.*/)?lenet_conv/",
+               r"fed_step/local_train/(.*[/(])?jvp\(lenet_conv\)",
                r"fed_step/local_train/(.*[/(])?"
-               r"transpose\(jvp\(conv_patches\)\)"]
+               r"transpose\(jvp\(lenet_conv\)\)"]
 ASYNC_SCOPES = [r"async_event/select/", r"async_event/batches/",
                 r"async_event/local_train/", r"async_event/contribute/",
                 r"async_event/aggregate/flush/",
