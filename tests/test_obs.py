@@ -14,6 +14,7 @@ import pytest
 from repro import api
 from repro.api import ExecSpec, RunResult, Scenario
 from repro.core.fedhc import FLRunConfig
+from repro.data.synthetic import CIFAR_LIKE
 from repro.obs.telemetry import (RunTelemetry, Telemetry,
                                  load_chrome_trace)
 from repro.obs.trace import COUNTERS, Counters, Tracer
@@ -229,11 +230,15 @@ def scan_ops():
         "fedhc-async": _scan_ops(_scan_hlo("fedhc-async", async_cohort=4,
                                            async_buffer=3)),
         "c-fedavg": _scan_ops(_scan_hlo("c-fedavg")),
+        # the 3-channel 32x32 path: LeNet's grouped conv over 3 input
+        # channels, at CIFAR widths
+        "fedhc-cifar": _scan_ops(_scan_hlo("fedhc", dataset=CIFAR_LIKE)),
     }
 
 
 @pytest.mark.parametrize("method, scopes", [("fedhc", SYNC_SCOPES),
-                                            ("fedhc-async", ASYNC_SCOPES)])
+                                            ("fedhc-async", ASYNC_SCOPES),
+                                            ("fedhc-cifar", SYNC_SCOPES)])
 def test_every_phase_scope_in_the_telemetry_off_scan(scan_ops, method,
                                                      scopes):
     names = [full for _, full, _ in scan_ops[method]]
@@ -241,7 +246,8 @@ def test_every_phase_scope_in_the_telemetry_off_scan(scan_ops, method,
         assert any(re.search(scope, n) for n in names), scope
 
 
-@pytest.mark.parametrize("method", ["fedhc", "fedhc-async", "c-fedavg"])
+@pytest.mark.parametrize("method", ["fedhc", "fedhc-async", "c-fedavg",
+                                    "fedhc-cifar"])
 def test_no_compute_op_of_the_scan_body_outside_a_phase(scan_ops, method):
     ops = scan_ops[method]
     # the scan's own loop body: its counter, its slice of the round
