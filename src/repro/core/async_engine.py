@@ -82,7 +82,7 @@ from repro.core import staleness as stale_lib
 from repro.core import strategies as strat_lib
 from repro.core.engine import SimData
 from repro.core.fedhc import FLRunConfig, _local_train
-from repro.data.synthetic import client_batches
+from repro.data.synthetic import client_batches, gather_samples, sample_rows
 from repro.launch import mesh as mesh_lib
 from repro.models.lenet import lenet_accuracy
 from repro.obs.telemetry import Telemetry
@@ -265,6 +265,7 @@ def _scan_fn_cached(cfg: FLRunConfig, mesh, client_axes):
     #                             no gather/scatter, sync-style cost
     #                             reduction, bit-compatible trajectory
     m = cfg.rounds_per_global
+    img_shape = (cfg.dataset.img, cfg.dataset.img, cfg.dataset.channels)
     constellation = engine._constellation_for(c)
     lp, cp = LinkParams(), cost_lib.ComputeParams()
     use_pallas = cfg.use_pallas_kernels
@@ -285,6 +286,8 @@ def _scan_fn_cached(cfg: FLRunConfig, mesh, client_axes):
             return x
 
     def run_scan(state0: AsyncState, data: SimData):
+        with phase_scope("async_event/batches"):
+            rows = sample_rows(data.images)     # once a call, not an event
         model_bits = _model_bits(state0.work_params, c)
         if sharded:
             pspecs = shard_rules.tree_param_specs(
@@ -328,9 +331,9 @@ def _scan_fn_cached(cfg: FLRunConfig, mesh, client_axes):
             # ---- 2. train the cohort on its fetched bases ----------------
             if full:
                 with phase_scope("async_event/batches"):
-                    imgs, labs = client_batches(data.images, data.labels,
+                    imgs, labs = client_batches(rows, data.labels,
                                                 data.client_idx, r_rnd,
-                                                cfg.batch_size)
+                                                cfg.batch_size, img_shape)
                     imgs, labs = shard_clients(imgs), shard_clients(labs)
                 with phase_scope("async_event/local_train"):
                     trained, l_new = _local_train(
@@ -352,7 +355,8 @@ def _scan_fn_cached(cfg: FLRunConfig, mesh, client_axes):
                     flat = jnp.take_along_axis(data.client_idx, picks,
                                                axis=1)
                     flat_c = flat[cohort_idx]
-                    imgs, labs = data.images[flat_c], data.labels[flat_c]
+                    imgs = gather_samples(rows, flat_c, img_shape)
+                    labs = data.labels[flat_c]
                     base = jax.tree_util.tree_map(lambda x: x[cohort_idx],
                                                   state.work_params)
                 # cohort stacks are gather products with no pinned layout,

@@ -86,7 +86,8 @@ from repro.core import clustering as cl
 from repro.core import strategies as strat_lib
 from repro.core.fedhc import (FLRunConfig, _adapt_members, _local_train,
                               _meta_update_clusters)
-from repro.data.synthetic import client_batches, dirichlet_partition, make_split
+from repro.data.synthetic import (client_batches, dirichlet_partition,
+                                  gather_samples, make_split, sample_rows)
 from repro.launch import mesh as mesh_lib
 from repro.models.lenet import init_lenet, lenet_accuracy, lenet_loss
 from repro.obs.telemetry import Telemetry
@@ -127,7 +128,8 @@ class RoundOutput(NamedTuple):
 
 class SimData(NamedTuple):
     """Per-experiment arrays the rounds read but never mutate."""
-    images: jnp.ndarray        # (N, H, W, ch) training pool
+    images: jnp.ndarray        # (N, H, W, ch) training pool; the scans
+    #                            gather its rows (`data/synthetic.py`)
     labels: jnp.ndarray        # (N,)
     test_x: jnp.ndarray
     test_y: jnp.ndarray
@@ -385,6 +387,7 @@ def _scan_fn_cached(cfg: FLRunConfig, mesh, client_axes):
     constellation = _constellation_for(cfg.num_clients)
     lp, cp = LinkParams(), cost_lib.ComputeParams()
     sample_bits = ds.img ** 2 * ds.channels * 32.0
+    img_shape = (ds.img, ds.img, ds.channels)
     use_pallas = cfg.use_pallas_kernels
     telem_on = cfg.telemetry    # emit repro.obs Telemetry as extra scan
     #                             outputs; the fed_step/* phase scopes
@@ -417,6 +420,8 @@ def _scan_fn_cached(cfg: FLRunConfig, mesh, client_axes):
                                    if sharded else 1))
 
     def run_scan(state0: RoundState, data: SimData):
+        with phase_scope("fed_step/batches"):
+            rows = sample_rows(data.images)     # once a call, not a round
         model_bits = sum(
             x.size for x in jax.tree_util.tree_leaves(state0.params))
         if not strategy.centralized:
@@ -475,9 +480,9 @@ def _scan_fn_cached(cfg: FLRunConfig, mesh, client_axes):
                 cadence_due = (rnd + 1) % cfg.rounds_per_global == 0
 
             with phase_scope("fed_step/batches"):
-                imgs, labs = client_batches(data.images, data.labels,
+                imgs, labs = client_batches(rows, data.labels,
                                             data.client_idx, r_rnd,
-                                            cfg.batch_size)
+                                            cfg.batch_size, img_shape)
                 imgs, labs = shard_clients(imgs), shard_clients(labs)
 
             with phase_scope("fed_step/orbit"):
@@ -693,7 +698,8 @@ def _scan_fn_cached(cfg: FLRunConfig, mesh, client_axes):
                 b = jax.random.fold_in(r_rnd, s)
                 picks = jax.random.randint(b, (cfg.batch_size,), 0, n_total)
                 l, g = jax.value_and_grad(lenet_loss)(
-                    model, (data.images[picks], data.labels[picks]))
+                    model, (gather_samples(rows, picks, img_shape),
+                            data.labels[picks]))
                 model = jax.tree_util.tree_map(
                     lambda a, gg: a - cfg.lr * gg, model, g)
                 return model, l
@@ -710,7 +716,8 @@ def _scan_fn_cached(cfg: FLRunConfig, mesh, client_axes):
                         jax.random.fold_in(r_rnd, 0), (cfg.batch_size,), 0,
                         n_total)
                     loss_val = lenet_loss(
-                        model, (data.images[picks], data.labels[picks]))
+                        model, (gather_samples(rows, picks, img_shape),
+                                data.labels[picks]))
 
             with phase_scope("fed_step/orbit"):
                 participating = jnp.ones((cfg.num_clients,), bool)

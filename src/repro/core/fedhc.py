@@ -50,7 +50,8 @@ from repro.core import clustering as cl
 from repro.core import maml as maml_lib
 from repro.core import strategies as strat_lib
 from repro.data.synthetic import (DatasetSpec, MNIST_LIKE, client_batches,
-                                  dirichlet_partition, make_split)
+                                  dirichlet_partition, make_split,
+                                  sample_rows)
 from repro.models.lenet import init_lenet, lenet_accuracy, lenet_loss
 from repro.orbits import cost as cost_lib
 from repro.orbits.constellation import Constellation, ground_station_position
@@ -443,14 +444,15 @@ def run_fl_legacy(cfg: FLRunConfig, verbose: bool = False) -> Dict[str, list]:
     t_sim, e_sim = 0.0, 0.0
     centralized = w0 if cfg.method == "c-fedavg" else None
 
+    rows = sample_rows(images)
     for rnd in range(cfg.rounds):
         r_rnd = jax.random.fold_in(r_loop, rnd)
         positions = constellation.positions(t_sim)
         gs = ground_station_position(t_s=t_sim)
         do_global = (rnd + 1) % cfg.rounds_per_global == 0
 
-        imgs, labs = client_batches(images, labels, client_idx, r_rnd,
-                                    cfg.batch_size)
+        imgs, labs = client_batches(rows, labels, client_idx, r_rnd,
+                                    cfg.batch_size, images.shape[1:])
 
         if cfg.method == "c-fedavg":
             # centralized: the server performs all clients' steps serially

@@ -100,9 +100,32 @@ def dirichlet_partition(rng, labels: jnp.ndarray, num_clients: int,
     return order[starts[cls] + idx_in_class].astype(jnp.int32)
 
 
-def client_batches(images, labels, client_idx, rng, batch_size: int):
-    """Sample one minibatch per client: returns ((C,B,H,W,ch), (C,B))."""
+def sample_rows(images):
+    """The sample pool as rows, (n, H, W, C) -> (n, C*H*W), one sample a
+    row in channel-major order; :func:`gather_samples` reads them back.
+
+    A TPU keeps a 4-D image array with the sample axis minor, on the
+    lanes, so a gather from it picks lanes at every pixel.  Rows are
+    gathered whole.  They are written as the transpose of the pool's
+    (C*H*W, n) view, which the TPU turns into one retiling and one 2-D
+    transpose, made once a call ahead of the round loop."""
+    n = images.shape[0]
+    return jnp.transpose(images, (3, 1, 2, 0)).reshape(-1, n).T
+
+
+def gather_samples(rows, idx, img_shape):
+    """Samples ``idx`` (any shape) of a row pool (:func:`sample_rows`),
+    as ``idx.shape + img_shape`` (H, W, C) images."""
+    h, w, c = img_shape
+    return jnp.moveaxis(rows[idx].reshape(idx.shape + (c, h, w)), -3, -1)
+
+
+def client_batches(rows, labels, client_idx, rng, batch_size: int,
+                   img_shape):
+    """Sample one minibatch per client from a row pool
+    (:func:`sample_rows`) of ``img_shape`` (H, W, ch) images: returns
+    ((C,B,H,W,ch), (C,B))."""
     num_clients, spc = client_idx.shape
     picks = jax.random.randint(rng, (num_clients, batch_size), 0, spc)
     flat = jnp.take_along_axis(client_idx, picks, axis=1)            # (C,B)
-    return images[flat], labels[flat]
+    return gather_samples(rows, flat, img_shape), labels[flat]

@@ -10,9 +10,9 @@ from proptest import given, settings, st
 
 from repro import checkpoint as _  # noqa: F401
 from repro.checkpoint.checkpoint import restore, save
-from repro.data.synthetic import (MNIST_LIKE, client_batches,
-                                  dirichlet_partition, make_dataset,
-                                  make_split)
+from repro.data.synthetic import (CIFAR_LIKE, MNIST_LIKE, client_batches,
+                                  dirichlet_partition, gather_samples,
+                                  make_dataset, make_split, sample_rows)
 from repro.optim import adam_init, adam_update, sgd_init, sgd_update
 
 
@@ -59,13 +59,32 @@ def test_dirichlet_partition_non_iid():
     assert np.asarray(idx).max() < 4000
 
 
-def test_client_batches_shapes():
-    x, y = make_dataset(jax.random.PRNGKey(1), MNIST_LIKE, 512)
+@pytest.mark.parametrize("picks", ["sync", "async_cohort"])
+@pytest.mark.parametrize("spec", [MNIST_LIKE, CIFAR_LIKE],
+                         ids=["mnist", "cifar"])
+def test_client_batches_shapes(spec, picks):
+    """The row gather the engines use returns, bit for bit, the 4-D
+    pool's samples at the same picks: the sync round's one minibatch
+    per client (``client_batches``) and the async cohort's (the cohort's
+    rows of the full-width picks)."""
+    x, y = make_dataset(jax.random.PRNGKey(1), spec, 512)
     idx = dirichlet_partition(jax.random.PRNGKey(2), y, 4,
                               samples_per_client=64)
-    bx, by = client_batches(x, y, idx, jax.random.PRNGKey(3), 16)
-    assert bx.shape == (4, 16, 28, 28, 1)
-    assert by.shape == (4, 16)
+    rows = sample_rows(x)
+    img_shape = (spec.img, spec.img, spec.channels)
+    assert rows.shape == (512, spec.img * spec.img * spec.channels)
+    key = jax.random.PRNGKey(3)
+    flat = jnp.take_along_axis(
+        idx, jax.random.randint(key, (4, 16), 0, 64), axis=1)
+    if picks == "sync":
+        bx, by = client_batches(rows, y, idx, key, 16, img_shape)
+    else:
+        flat = flat[jnp.array([0, 2, 3])]
+        bx, by = gather_samples(rows, flat, img_shape), y[flat]
+    assert bx.shape == flat.shape + img_shape
+    assert by.shape == flat.shape
+    np.testing.assert_array_equal(np.asarray(bx), np.asarray(x[flat]))
+    np.testing.assert_array_equal(np.asarray(by), np.asarray(y[flat]))
 
 
 # -------------------------------------------------------------- optim ----

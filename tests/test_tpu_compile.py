@@ -13,6 +13,8 @@ imports this file.  Code that asks ``jax.default_backend()`` still sees
 the CPU, so the tests steer the kernel wrappers out of interpret mode
 themselves.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -95,4 +97,11 @@ def test_paper_scale_fedhc_scan_fits_one_v5e(one_chip, monkeypatch, pallas):
     need = (ma.temp_size_in_bytes + ma.argument_size_in_bytes
             + ma.output_size_in_bytes)
     assert need + HBM_HEADROOM_BYTES <= V5E_HBM_BYTES, need
-    assert ("tpu_custom_call" in compiled.as_text()) == pallas
+    text = compiled.as_text()
+    assert ("tpu_custom_call" in text) == pallas
+    # the minibatch gather copies whole 784-wide sample rows; gathered
+    # as (1, 28, 28) slabs of the 4-D pool it picks lanes, the sample
+    # axis being minor in the TPU's layout of that pool
+    sizes = set(re.findall(r" gather\(.*slice_sizes=(\{[\d,]*\})"
+                           r".*op_name=\"[^\"]*fed_step/batches/", text))
+    assert "{1,784}" in sizes and "{1,28,28}" not in sizes, sizes
